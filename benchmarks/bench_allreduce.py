@@ -21,8 +21,9 @@ from repro.core import MeshSpec, trace_from_hlo
 from repro.core.costmodel import allreduce_time
 from repro.core.topology import V5E
 from repro.distributed.algorithms import ALGORITHMS, allreduce_fn
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 spec = MeshSpec((8,), ("data",))
 NB = 1 << 22          # 4 MiB payload
 x = jnp.ones((8, NB // 4 // 8), jnp.float32)

@@ -20,12 +20,13 @@ from repro.core import MeshSpec, trace_from_hlo
 from repro.core.report import top_contenders_table, semantic_table
 from repro.distributed import sharding as sh
 from repro.distributed.autoshard import activation_sharding
+from repro.launch.mesh import make_mesh
 from repro.launch.presets import StepSettings
 from repro.launch.steps import make_train_step
 from repro.models import api
 from repro.optim import adamw
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 spec = MeshSpec((2, 4), ("data", "model"))
 rows = []
 for arch in ("chatglm3-6b", "mixtral-8x22b"):

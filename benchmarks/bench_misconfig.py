@@ -19,9 +19,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.core import MeshSpec, trace_from_hlo, detect
+from repro.launch.mesh import make_mesh
 
 D_AX, M_AX = 2, 4
-mesh = jax.make_mesh((D_AX, M_AX), ("data", "model"))
+mesh = make_mesh((D_AX, M_AX), ("data", "model"))
 spec = MeshSpec((D_AX, M_AX), ("data", "model"))
 L, B, S, D, F = 8, 8, 256, 512, 1024
 

@@ -18,11 +18,11 @@ import json
 import time
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.core import MeshSpec, trace_from_hlo
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((8,), ("model",))
+mesh = make_mesh((8,), ("model",))
 spec = MeshSpec((8,), ("model",))
 
 KINDS = {
@@ -41,8 +41,8 @@ for log2 in (10, 14, 18, 22, 26):
     x = jnp.zeros((8, n_elems // 8), jnp.float32)
     xd = jax.device_put(x, NamedSharding(mesh, P("model")))
     for kind, (f, out_spec) in KINDS.items():
-        fn = jax.jit(shard_map(f, mesh=mesh, in_specs=P("model"),
-                               out_specs=out_spec, check_rep=False))
+        fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("model"),
+                               out_specs=out_spec, check_vma=False))
         compiled = fn.lower(xd).compile()
         for _ in range(2):
             out = fn(xd)

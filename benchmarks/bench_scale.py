@@ -19,13 +19,14 @@ from repro.configs import ARCHS, smoke_config
 from repro.core import MeshSpec, trace_from_hlo
 from repro.distributed import sharding as sh
 from repro.distributed.autoshard import activation_sharding
+from repro.launch.mesh import make_mesh
 from repro.launch.presets import StepSettings
 from repro.launch.steps import make_train_step
 from repro.models import api
 from repro.optim import adamw
 
 D, M = %d, %d
-mesh = jax.make_mesh((D, M), ("data", "model"))
+mesh = make_mesh((D, M), ("data", "model"))
 spec = MeshSpec((D, M), ("data", "model"))
 cfg = smoke_config(ARCHS["mixtral-8x22b"]).replace(
     d_model=256, moe_d_ff=512, num_layers=4, vocab_size=1024,

@@ -15,6 +15,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import MeshSpec, detect, trace_from_hlo
 from repro.core.report import top_contenders_table
+from repro.launch.mesh import make_mesh
 
 L, B, S, D, F = 8, 8, 256, 512, 1024
 
@@ -37,7 +38,7 @@ def make_step(mesh, bug: bool):
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     spec = MeshSpec((2, 4), ("data", "model"))
     for label in ("good", "bad"):
         g = jax.jit(jax.value_and_grad(make_step(mesh, label == "bad"),

@@ -13,16 +13,15 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import dataclasses
 
-import jax
-
 from repro.core import MeshSpec
 from repro.core.diff import render_diff
 from repro.launch import presets
 from repro.launch.dryrun import lower_cell
+from repro.launch.mesh import make_mesh
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     spec = MeshSpec((2, 4), ("data", "model"))
     arch, shape = "mixtral-8x22b", "decode_32k"
 

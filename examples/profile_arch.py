@@ -13,11 +13,10 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import argparse
 
-import jax
-
 from repro.core import MeshSpec
 from repro.core.report import semantic_table, summary, to_html, top_contenders_table
 from repro.launch.dryrun import lower_cell
+from repro.launch.mesh import make_mesh
 
 
 def main():
@@ -27,7 +26,7 @@ def main():
     ap.add_argument("--out", default="/tmp/repro_trace.html")
     args = ap.parse_args()
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     spec = MeshSpec((2, 4), ("data", "model"))
     print(f"tracing {args.arch} x {args.shape} on a 2x4 host mesh ...")
     r = lower_cell(args.arch, args.shape, mesh=mesh, mesh_spec=spec)

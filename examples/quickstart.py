@@ -18,6 +18,7 @@ from repro.core.report import (semantic_table, summary, timeline,
                                top_contenders_table)
 from repro.distributed import sharding as sh
 from repro.distributed.autoshard import activation_sharding
+from repro.launch.mesh import make_mesh
 from repro.launch.presets import StepSettings
 from repro.launch.steps import make_train_step
 from repro.models import api
@@ -28,7 +29,7 @@ def main():
     cfg = smoke_config(ARCHS["chatglm3-6b"]).replace(
         d_model=256, d_ff=512, num_layers=6, vocab_size=1024,
         num_heads=8, num_kv_heads=4, head_dim=32)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     spec = MeshSpec((2, 4), ("data", "model"))
 
     step = make_train_step(cfg, adamw.AdamWConfig(),

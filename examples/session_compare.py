@@ -24,6 +24,7 @@ def real_traces():
     from repro.core import trace_from_hlo
     from repro.distributed import sharding as sh
     from repro.distributed.autoshard import activation_sharding
+    from repro.launch.mesh import make_mesh
     from repro.launch.presets import StepSettings
     from repro.launch.steps import make_train_step
     from repro.models import api
@@ -35,7 +36,7 @@ def real_traces():
             ("dp8", (8, 1), ("data", "model")),
             ("dp4xtp2", (4, 2), ("data", "model")),
             ("dp2xtp4", (2, 4), ("data", "model"))):
-        mesh = jax.make_mesh(shape, axes)
+        mesh = make_mesh(shape, axes)
         spec = MeshSpec(shape, axes)
         cfg = smoke_config(ARCHS["chatglm3-6b"]).replace(
             d_model=128, d_ff=256, num_layers=4, vocab_size=512,

@@ -181,9 +181,15 @@ def annotate_store(store, mesh: MeshSpec, hw: Hardware) -> None:
     store.wire_bytes_per_device = wire
 
     # ---- protocol regime + completion time --------------------------------
-    eager = per_shard < hw.rndv_threshold
-    proto_codes = np.where(eager, np.int32(0), np.int32(1))
-    store.protocol = Categorical(proto_codes, ["eager", "rndv"])
+    # vocab in first-seen order, as `TraceStore.from_events` interns it,
+    # so the store matches the per-event path's `identical`-ly
+    rndv = (per_shard >= hw.rndv_threshold).astype(np.int32)
+    seen, first = np.unique(rndv, return_index=True)
+    order = seen[np.argsort(first)]
+    lut = np.zeros(2, dtype=np.int32)
+    lut[order] = np.arange(len(order), dtype=np.int32)
+    store.protocol = Categorical(lut[rndv],
+                                 [("eager", "rndv")[r] for r in order])
 
     eff_bw = 2.0 * bw
     t_bw = np.divide(wire, eff_bw, out=np.zeros(n, dtype=np.float64),

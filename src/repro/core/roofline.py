@@ -27,6 +27,7 @@ class RooflineReport:
     hlo_flops: float
     hlo_bytes: float
     collective_bytes: float
+    hw: Hardware                   # the chip whose peaks set the terms
     model_flops: float = 0.0
     per_device_memory_bytes: float = 0.0
 
@@ -64,7 +65,7 @@ class RooflineReport:
         """
         if not self.bound_s:
             return 0.0
-        ideal = self.model_flops / (self.chips * V5E.flops_bf16)
+        ideal = self.model_flops / (self.chips * self.hw.flops_bf16)
         return ideal / self.bound_s
 
     def row(self) -> Dict[str, object]:
@@ -106,6 +107,7 @@ def roofline(trace: Trace, hw: Hardware = V5E,
         hlo_flops=trace.hlo_flops,
         hlo_bytes=trace.hlo_bytes,
         collective_bytes=coll_bytes,
+        hw=hw,
         model_flops=model_flops,
         per_device_memory_bytes=trace.per_device_memory_bytes,
     )
@@ -113,8 +115,7 @@ def roofline(trace: Trace, hw: Hardware = V5E,
 
 def kernel_adjusted(rf: RooflineReport, trace: Trace, scope_pattern: str,
                     new_bytes: float, new_flops: Optional[float] = None,
-                    hw: Hardware = V5E, label_suffix: str = "+kernel"
-                    ) -> RooflineReport:
+                    label_suffix: str = "+kernel") -> RooflineReport:
     """Roofline with one scope's XLA implementation replaced by a Pallas
     kernel's analytic traffic/FLOPs.
 
@@ -137,12 +138,13 @@ def kernel_adjusted(rf: RooflineReport, trace: Trace, scope_pattern: str,
     return RooflineReport(
         label=rf.label + label_suffix,
         chips=rf.chips,
-        compute_s=new_hlo_flops / hw.flops_bf16,
-        memory_s=new_hbm_bytes / hw.hbm_bw,
+        compute_s=new_hlo_flops / rf.hw.flops_bf16,
+        memory_s=new_hbm_bytes / rf.hw.hbm_bw,
         collective_s=rf.collective_s,
         hlo_flops=new_hlo_flops,
         hlo_bytes=new_hbm_bytes,
         collective_bytes=rf.collective_bytes,
+        hw=rf.hw,
         model_flops=rf.model_flops,
         per_device_memory_bytes=rf.per_device_memory_bytes,
     )
@@ -166,6 +168,7 @@ def scenario_adjusted(rf: RooflineReport, result) -> RooflineReport:
         hlo_flops=rf.hlo_flops,
         hlo_bytes=rf.hlo_bytes,
         collective_bytes=result.wire,
+        hw=rf.hw,
         model_flops=rf.model_flops,
         per_device_memory_bytes=rf.per_device_memory_bytes,
     )

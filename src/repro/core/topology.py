@@ -16,23 +16,45 @@ import numpy as np
 
 @dataclass(frozen=True)
 class Hardware:
-    """TPU v5e-class constants (per chip / per link)."""
+    """Per-chip peaks and per-link constants of one TPU generation."""
 
-    name: str = "tpu-v5e"
-    flops_bf16: float = 197e12          # peak bf16 FLOP/s per chip
-    hbm_bw: float = 819e9               # HBM bytes/s per chip
-    ici_bw: float = 50e9                # bytes/s per ICI link (per direction)
-    dci_bw: float = 25e9                # bytes/s per inter-pod link
-    ici_latency_s: float = 1e-6         # per-hop collective latency
-    dci_latency_s: float = 10e-6
-    hbm_per_chip: float = 16e9          # v5e HBM capacity
-    vmem_per_core: float = 128 * 2**20  # VMEM bytes
+    name: str
+    flops_bf16: float                   # peak bf16 FLOP/s per chip
+    hbm_bw: float                       # HBM bytes/s per chip
+    ici_bw: float                       # bytes/s per ICI link (per direction)
+    dci_bw: float                       # bytes/s per inter-pod link
+    ici_latency_s: float                # per-hop collective latency
+    dci_latency_s: float
+    hbm_per_chip: float                 # HBM capacity, bytes
+    vmem_per_core: float                # VMEM bytes
     # eager/rendezvous analogue: below this payload a transfer is
     # latency-dominated ("eager"), above it bandwidth-dominated ("rndv").
     rndv_threshold: int = 1 << 16
 
 
-V5E = Hardware()
+# Peaks from Google Cloud's "TPU v5e" documentation: 197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s, 1,600 Gbit/s of ICI per chip (4 links, so
+# 50 GB/s each way per link).  The latencies, the DCI link and the
+# rendezvous threshold are modelling assumptions, not measurements.
+V5E = Hardware(name="TPU v5 lite", flops_bf16=197e12, hbm_bw=819e9,
+               ici_bw=50e9, dci_bw=25e9, ici_latency_s=1e-6,
+               dci_latency_s=10e-6, hbm_per_chip=16e9,
+               vmem_per_core=128 * 2**20)
+
+# `device_kind` as jax reports it -> that chip's Hardware.  Offline
+# analysis of HLO text prices against V5E; the chip path looks its device
+# up here, and a device not in the table is an error, not a default.
+PEAKS: Dict[str, Hardware] = {"TPU v5 lite": V5E}
+
+
+def hardware_for(device_kind: str) -> Hardware:
+    """The `Hardware` of a device kind; raises for a kind not in PEAKS."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks for device_kind {device_kind!r}: add it to "
+            f"topology.PEAKS with its published source") from None
 
 
 @dataclass(frozen=True)

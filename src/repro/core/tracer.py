@@ -16,7 +16,7 @@ from typing import Any, Callable, Dict, Optional
 
 from repro.core import attribution, costmodel, hlo_parser
 from repro.core.events import Trace
-from repro.core.topology import Hardware, MeshSpec, V5E
+from repro.core.topology import Hardware, MeshSpec, V5E, hardware_for
 
 
 def trace_from_hlo(hlo_text: str, mesh: MeshSpec, *, label: str = "step",
@@ -28,6 +28,10 @@ def trace_from_hlo(hlo_text: str, mesh: MeshSpec, *, label: str = "step",
                    shard_workers: Optional[int] = None,
                    recover: bool = False) -> Trace:
     """Assemble a multi-layer trace from compiled HLO text.
+
+    HLO text does not name its device, so it is priced on `hw` (v5e
+    unless told otherwise); `trace_compiled`/`trace_step` look the
+    executable's own device up in `topology.PEAKS` instead.
 
     `engine` selects the ingest pipeline:
       * `"columnar"` (default) — single-pass parse straight into
@@ -82,9 +86,6 @@ def trace_from_hlo(hlo_text: str, mesh: MeshSpec, *, label: str = "step",
     # bodies once); fall back to cost_analysis when parsing finds nothing.
     tr.hlo_flops = float(stats.flops)
     tr.hlo_bytes = float(stats.bytes_accessed)
-    if isinstance(cost_analysis, (list, tuple)):
-        # older jax: Compiled.cost_analysis() returns [per-module dict]
-        cost_analysis = cost_analysis[0] if cost_analysis else None
     if cost_analysis:
         ca_flops = float(cost_analysis.get("flops", 0.0))
         ca_bytes = float(cost_analysis.get("bytes accessed", 0.0))
@@ -114,21 +115,43 @@ class TraceResult:
     hlo_chars: int
 
 
+def compiled_hardware(compiled) -> Hardware:
+    """The `Hardware` of the devices a jax `Compiled` runs on.
+
+    Raises for a device kind that `topology.PEAKS` does not list.
+    """
+    import jax
+
+    shardings = jax.tree.leaves((compiled.input_shardings,
+                                 compiled.output_shardings))
+    kinds = {d.device_kind for sh in shardings for d in sh.device_set}
+    if len(kinds) != 1:
+        raise ValueError(f"executable spans device kinds {sorted(kinds)}")
+    return hardware_for(kinds.pop())
+
+
 def trace_compiled(compiled, mesh: MeshSpec, *, label: str = "step",
-                   hw: Hardware = V5E) -> Trace:
-    """Trace an already-compiled step (jax Compiled object)."""
-    text = compiled.as_text()
-    ca = compiled.cost_analysis()
-    ma = compiled.memory_analysis()
-    tr = trace_from_hlo(text, mesh, label=label, hw=hw,
-                        cost_analysis=ca, memory_analysis=ma)
-    return tr
+                   hw: Optional[Hardware] = None, engine: str = "columnar",
+                   shards: Optional[int] = None) -> Trace:
+    """Trace an already-compiled step (jax Compiled object).
+
+    `hw=None` prices on the executable's own device (`compiled_hardware`).
+    """
+    return trace_from_hlo(compiled.as_text(), mesh, label=label,
+                          hw=hw or compiled_hardware(compiled),
+                          cost_analysis=compiled.cost_analysis(),
+                          memory_analysis=compiled.memory_analysis(),
+                          engine=engine, shards=shards)
 
 
 def trace_step(fn: Callable, args_specs, mesh_jax, mesh_spec: MeshSpec, *,
                in_shardings=None, out_shardings=None, label="step",
-               hw: Hardware = V5E, donate_argnums=()) -> TraceResult:
-    """Lower + compile `fn` on `mesh_jax` and assemble the trace."""
+               hw: Optional[Hardware] = None,
+               donate_argnums=()) -> TraceResult:
+    """Lower + compile `fn` on `mesh_jax` and assemble the trace.
+
+    `hw=None` prices on the compiled step's own device.
+    """
     import jax
 
     t0 = time.perf_counter()
@@ -139,12 +162,8 @@ def trace_step(fn: Callable, args_specs, mesh_jax, mesh_spec: MeshSpec, *,
         t1 = time.perf_counter()
         compiled = lowered.compile()
     t2 = time.perf_counter()
-    text = compiled.as_text()
-    ca = compiled.cost_analysis()
-    ma = compiled.memory_analysis()
-    tr = trace_from_hlo(text, mesh_spec, label=label, hw=hw,
-                        cost_analysis=ca, memory_analysis=ma)
+    tr = trace_compiled(compiled, mesh_spec, label=label, hw=hw)
     t3 = time.perf_counter()
     return TraceResult(trace=tr, compiled=compiled, lowered=lowered,
                        lower_s=t1 - t0, compile_s=t2 - t1, parse_s=t3 - t2,
-                       hlo_chars=len(text))
+                       hlo_chars=len(compiled.as_text()))

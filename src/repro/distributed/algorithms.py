@@ -13,7 +13,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -113,8 +112,8 @@ def allreduce_fn(algorithm: str, mesh, axis_name: str = "data",
     """shard_map-wrapped allreduce over one mesh axis."""
     fn = ALGORITHMS[algorithm]
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=P(axis_name),
-                       out_specs=P(axis_name), check_rep=False)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P(axis_name),
+                       out_specs=P(axis_name), check_vma=False)
     def run(shard):
         return fn(shard, axis_name)
 

@@ -27,7 +27,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -99,7 +98,7 @@ def apply_moe_sort(cfg, p, x, mesh):
     bspec = data_axes[0] if len(data_axes) == 1 else data_axes
 
     fn = functools.partial(_moe_shard, cfg=cfg, e_loc=e_loc)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(bspec, None, None),        # x: tokens over data
                   P(None, None),               # router replicated
@@ -107,6 +106,6 @@ def apply_moe_sort(cfg, p, x, mesh):
                   P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(bspec, None, None), P()),
-        check_rep=False)
+        check_vma=False)
     y, aux = mapped(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y, aux

@@ -16,7 +16,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -59,11 +58,11 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_micro, mesh,
             jnp.where(idx == p_size - 1, out, jnp.zeros_like(out)), axis)
         return out
 
-    mapped = shard_map(
+    mapped = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(axis), P()),     # params split by stage; micros replicated
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return mapped(stage_params, x_micro)
 
 

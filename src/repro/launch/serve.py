@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import api as model_api
 from repro.models import transformer
 
@@ -101,6 +102,7 @@ def main():
     ap.add_argument("--max-batch", type=int, default=4)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)

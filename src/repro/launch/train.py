@@ -18,6 +18,7 @@ import argparse
 import os
 import signal
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,8 @@ from repro.configs import get_config, smoke_config
 from repro.data import DataConfig, SyntheticTokens
 from repro.distributed import sharding as shlib
 from repro.distributed.autoshard import activation_sharding
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.launch.presets import StepSettings
 from repro.launch.steps import make_train_step
 from repro.models import api as model_api
@@ -56,6 +59,10 @@ class Trainer:
         self._preempted = False
 
         self.step_fn = make_train_step(cfg, self.opt_cfg, self.settings)
+        # the executable every step runs, compiled ahead of the first one
+        # (`compile`) so that profilers trace this very program
+        self.compiled = None
+        self.compile_s = None
         if mesh is not None:
             pspecs = shlib.param_pspecs(cfg, mesh)
             psh = shlib.named(mesh, pspecs)
@@ -64,17 +71,21 @@ class Trainer:
             self.jit_step = jax.jit(self.step_fn, donate_argnums=(0, 1),
                                     in_shardings=(psh, osh, None),
                                     out_shardings=(psh, osh, None))
-            self.param_sh = psh
+            self.param_sh, self.opt_sh = psh, osh
         else:
             self.jit_step = jax.jit(self.step_fn, donate_argnums=(0, 1))
-            self.param_sh = None
+            self.param_sh = self.opt_sh = None
 
     # ---- state ------------------------------------------------------------
     def init_state(self, seed=0):
-        params = model_api.init_params(self.cfg, seed)
-        if self.param_sh is not None:
-            params = jax.device_put(params, self.param_sh)
-        opt = adamw.init(self.opt_cfg, params)
+        if self.param_sh is None:
+            params = model_api.init_params(self.cfg, seed)
+            return params, adamw.init(self.opt_cfg, params), 0
+        # built in place on the mesh: no device ever holds the whole model
+        params = jax.jit(lambda: model_api.init_params(self.cfg, seed),
+                         out_shardings=self.param_sh)()
+        opt = jax.jit(lambda p: adamw.init(self.opt_cfg, p),
+                      out_shardings=self.opt_sh)(params)
         return params, opt, 0
 
     def restore_or_init(self, seed=0):
@@ -83,10 +94,7 @@ class Trainer:
             tree = {"params": params, "opt": opt}
             sh = None
             if self.param_sh is not None:
-                sh = {"params": self.param_sh,
-                      "opt": {"m": self.param_sh, "v": self.param_sh,
-                              "count": jax.sharding.NamedSharding(
-                                  self.mesh, jax.sharding.PartitionSpec())}}
+                sh = {"params": self.param_sh, "opt": self.opt_sh}
             restored, extra = checkpoint.restore(self.ckpt_dir, tree,
                                                  shardings=sh)
             step = int(extra.get("next_step", 0))
@@ -103,6 +111,14 @@ class Trainer:
                                "arch": self.cfg.name})
         checkpoint.prune_old(self.ckpt_dir, keep=self.keep)
 
+    def compile(self, params, opt, batch):
+        """Lower + compile the step for these arguments (once)."""
+        if self.compiled is None:
+            t0 = time.perf_counter()
+            self.compiled = self.jit_step.lower(params, opt, batch).compile()
+            self.compile_s = time.perf_counter() - t0
+        return self.compiled
+
     # ---- loop -------------------------------------------------------------
     def run(self, seed=0) -> list:
         params, opt, start = self.restore_or_init(seed)
@@ -116,10 +132,12 @@ class Trainer:
             if ctx:
                 ctx.__enter__()
             for step in range(start, self.steps):
-                self.watchdog.start_step(step)
                 batch = {k: jnp.asarray(v)
                          for k, v in self.data.batch_at(step).items()}
-                params, opt, metrics = self.jit_step(params, opt, batch)
+                step_fn = self.compile(params, opt, batch)
+                self.watchdog.start_step(step)
+                params, opt, metrics = jax.block_until_ready(
+                    step_fn(params, opt, batch))
                 loss = float(metrics["loss"])
                 st = self.watchdog.end_step()
                 self.metrics_log.append(
@@ -165,13 +183,14 @@ def main():
     ap.add_argument("--accum", type=int, default=1)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = smoke_config(cfg)
     mesh = None
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     tr = Trainer(cfg, steps=args.steps, batch=args.batch, seq=args.seq,
                  ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
                  mesh=mesh, fail_at_step=args.fail_at_step,

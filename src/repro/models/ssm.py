@@ -3,9 +3,9 @@
 Training/prefill uses a chunked scan: an outer `lax.scan` over sequence
 chunks carries the recurrent state h [B, d_inner, N]; within a chunk the
 recurrence is evaluated with a numerically-stable `associative_scan`.
-The TPU hot path is the Pallas kernel in `repro.kernels.mamba_scan`
-(same chunking, explicit VMEM tiles); this module is the XLA reference
-used for CPU smoke tests and the dry-run.
+This XLA scan is the model path on every backend, the TPU included.
+The Pallas kernel in `repro.kernels.mamba_scan` (same chunking, explicit
+VMEM tiles) compiles for v5e but is not wired in here.
 
 Decode carries (conv_state [B, d_conv-1, d_inner], ssm_state [B, d_inner, N]).
 """

@@ -94,15 +94,16 @@ import tempfile
 import os
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro import checkpoint
+from repro.launch.mesh import make_mesh
 
 d = tempfile.mkdtemp()
-mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+mesh_a = make_mesh((2, 4), ("data", "model"))
 x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
 xa = jax.device_put(x, NamedSharding(mesh_a, P("data", "model")))
 checkpoint.save(d, 1, {"x": xa})
 
 for shape in [(4, 2), (8, 1), (1, 8)]:
-    mesh_b = jax.make_mesh(shape, ("data", "model"))
+    mesh_b = make_mesh(shape, ("data", "model"))
     sh = {"x": NamedSharding(mesh_b, P("data", "model"))}
     restored, _ = checkpoint.restore(d, {"x": x}, shardings=sh)
     np.testing.assert_array_equal(np.asarray(restored["x"]), np.asarray(x))

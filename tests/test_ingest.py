@@ -126,6 +126,30 @@ def test_ingest_empty_module():
     assert tr.events == []
 
 
+def test_ingest_identical_when_first_site_is_rendezvous():
+    """The columnar store interns `protocol` in first-seen order, as the
+    per-event path does: a module whose first collective is above the
+    rendezvous threshold (as in a compiled TPU train step) still gives
+    identical stores."""
+    def site(i, shape):
+        return (f"  %ar{i} = f32[{shape}] all-reduce(%p{i}), channel_id={i},"
+                f" replica_groups=[2,4]<=[8], to_apply=%add, "
+                f"metadata={{op_name=\"jit(f)/psum{i}\"}}\n")
+    text = ("HloModule rndv_first\n\n"
+            "%add (a: f32[], b: f32[]) -> f32[] {\n"
+            "  %a = f32[] parameter(0)\n  %b = f32[] parameter(1)\n"
+            "  ROOT %r = f32[] add(%a, %b)\n}\n\n"
+            "ENTRY %main (p1: f32[1024,1024], p2: f32[4]) -> f32[4] {\n"
+            "  %p1 = f32[1024,1024] parameter(0)\n"
+            "  %p2 = f32[4] parameter(1)\n"
+            + site(1, "1024,1024") + site(2, "4") +
+            "  ROOT %out = f32[4] copy(%ar2)\n}\n")
+    rows = trace_from_hlo(text, MESH, engine="rows")
+    fast = trace_from_hlo(text, MESH, engine="columnar")
+    assert [e.protocol for e in rows.events] == ["rndv", "eager"]
+    assert fast.store.identical(rows.store)
+
+
 # -- payload dedup + memoization --------------------------------------------
 
 def test_store_payload_dedup():

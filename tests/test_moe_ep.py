@@ -10,13 +10,14 @@ import numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.configs import ARCHS, smoke_config
 from repro.distributed.autoshard import activation_sharding
+from repro.launch.mesh import make_mesh
 from repro.models import api, moe as moe_mod
 from repro.models.meta import materialize
 
 cfg = smoke_config(ARCHS["qwen3-moe-235b-a22b"]).replace(
     num_experts=8, top_k=2,
     capacity_factor=4.0)     # = E/k: no drops in either path
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 
 meta = moe_mod.moe_meta(cfg)
 params = materialize(meta, jax.random.PRNGKey(0))

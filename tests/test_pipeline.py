@@ -17,9 +17,10 @@ import jax.numpy as jnp
 import numpy as np
 from repro.distributed.pipeline import pipeline_apply
 from repro.core import MeshSpec, trace_from_hlo
+from repro.launch.mesh import make_mesh
 
 P_STAGES, M, MB, D = 4, 6, 2, 32
-mesh = jax.make_mesh((4,), ("model",))
+mesh = make_mesh((4,), ("model",))
 rng = np.random.default_rng(0)
 w = jnp.asarray(rng.standard_normal((P_STAGES, D, D)) * 0.3, jnp.float32)
 x = jnp.asarray(rng.standard_normal((M, MB, D)), jnp.float32)

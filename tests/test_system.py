@@ -62,6 +62,7 @@ from repro.core import MeshSpec, roofline, trace_from_hlo
 from repro.core.report import top_contenders_table
 from repro.distributed import sharding as sh
 from repro.distributed.autoshard import activation_sharding
+from repro.launch.mesh import make_mesh
 from repro.launch.presets import StepSettings
 from repro.launch.steps import make_train_step
 from repro.models import api
@@ -70,7 +71,7 @@ from repro.optim import adamw
 cfg = smoke_config(ARCHS["chatglm3-6b"]).replace(
     d_model=128, d_ff=256, num_layers=4, vocab_size=512, num_heads=8,
     num_kv_heads=4, head_dim=16)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 spec = MeshSpec((2, 4), ("data", "model"))
 opt_cfg = adamw.AdamWConfig()
 st = StepSettings(accum=2, remat="full")
@@ -117,7 +118,8 @@ def test_dryrun_cell_small_mesh(subproc):
 import jax
 from repro.core import MeshSpec
 from repro.launch.dryrun import lower_cell
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 spec = MeshSpec((2, 4), ("data", "model"))
 r = lower_cell("hymba-1.5b", "decode_32k", mesh=mesh, mesh_spec=spec)
 assert "skipped" not in r, r
@@ -138,8 +140,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.core import MeshSpec, trace_from_hlo
 from repro.core import detect
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 spec = MeshSpec((2, 4), ("data", "model"))
 
 def step(w, x):
