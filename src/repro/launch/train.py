@@ -6,7 +6,13 @@ Features exercised by the integration tests:
   * crash injection (`--fail-at-step`) for restart-continuity testing,
   * SIGTERM preemption handler (checkpoint then exit 0),
   * straggler watchdog with step-time stats,
-  * optional mesh execution (`--mesh DxM`) over available devices.
+  * optional mesh execution (`--mesh DxM`) over available devices,
+  * profiler spans: each step is a `train.step` (its `step_num` gives
+    xprof/TensorBoard their step markers) tiled by the loop's phases
+    `train.data`, `train.dispatch`, `train.wait`, `train.fetch` and
+    `train.log`; `train.ckpt` marks each checkpoint save and
+    `train.compile` the step's one compile.  They record only while a
+    profiler trace is running (`jax.profiler.trace`).
 
 Run e.g.:
     PYTHONPATH=src python -m repro.launch.train --arch chatglm3-6b --smoke \
@@ -22,6 +28,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro import checkpoint
 from repro.configs import get_config, smoke_config
@@ -105,18 +112,20 @@ class Trainer:
     def save_ckpt(self, params, opt, next_step):
         if not self.ckpt_dir:
             return
-        checkpoint.save(self.ckpt_dir, next_step,
-                        {"params": params, "opt": opt},
-                        extra={"next_step": next_step,
-                               "arch": self.cfg.name})
-        checkpoint.prune_old(self.ckpt_dir, keep=self.keep)
+        with TraceAnnotation("train.ckpt"):
+            checkpoint.save(self.ckpt_dir, next_step,
+                            {"params": params, "opt": opt},
+                            extra={"next_step": next_step,
+                                   "arch": self.cfg.name})
+            checkpoint.prune_old(self.ckpt_dir, keep=self.keep)
 
     def compile(self, params, opt, batch):
         """Lower + compile the step for these arguments (once)."""
         if self.compiled is None:
-            t0 = time.perf_counter()
-            self.compiled = self.jit_step.lower(params, opt, batch).compile()
-            self.compile_s = time.perf_counter() - t0
+            with TraceAnnotation("train.compile"):
+                t0 = time.perf_counter()
+                self.compiled = self.jit_step.lower(params, opt, batch).compile()
+                self.compile_s = time.perf_counter() - t0
         return self.compiled
 
     # ---- loop -------------------------------------------------------------
@@ -132,33 +141,41 @@ class Trainer:
             if ctx:
                 ctx.__enter__()
             for step in range(start, self.steps):
-                batch = {k: jnp.asarray(v)
-                         for k, v in self.data.batch_at(step).items()}
-                step_fn = self.compile(params, opt, batch)
-                self.watchdog.start_step(step)
-                params, opt, metrics = jax.block_until_ready(
-                    step_fn(params, opt, batch))
-                loss = float(metrics["loss"])
-                st = self.watchdog.end_step()
-                self.metrics_log.append(
-                    {"step": step, "loss": loss,
-                     "grad_norm": float(metrics["grad_norm"]),
-                     "sec": st.duration_s, "straggler": st.flagged})
-                if step % self.log_every == 0 or step == self.steps - 1:
-                    print(f"[train] step {step:5d} loss {loss:.4f} "
-                          f"gnorm {float(metrics['grad_norm']):.3f} "
-                          f"({st.duration_s*1e3:.0f} ms)")
-                next_step = step + 1
-                if self.ckpt_every and next_step % self.ckpt_every == 0:
-                    self.save_ckpt(params, opt, next_step)
-                if self._preempted:
-                    print("[train] SIGTERM: checkpointing and exiting")
-                    self.save_ckpt(params, opt, next_step)
-                    sys.exit(0)
-                if self.fail_at_step is not None and next_step == self.fail_at_step:
-                    print(f"[train] injected failure at step {next_step}",
-                          flush=True)
-                    os._exit(42)   # simulate a hard node crash
+                # the phases tile the step, so a device-idle gap in a
+                # trace falls in the phase that kept the host busy
+                with StepTraceAnnotation("train.step", step_num=step):
+                    with TraceAnnotation("train.data"):
+                        batch = {k: jnp.asarray(v)
+                                 for k, v in self.data.batch_at(step).items()}
+                    with TraceAnnotation("train.dispatch"):
+                        step_fn = self.compile(params, opt, batch)
+                        self.watchdog.start_step(step)
+                        params, opt, metrics = step_fn(params, opt, batch)
+                    with TraceAnnotation("train.wait"):
+                        jax.block_until_ready((params, opt, metrics))
+                    with TraceAnnotation("train.fetch"):
+                        loss = float(metrics["loss"])
+                        gnorm = float(metrics["grad_norm"])
+                    with TraceAnnotation("train.log"):
+                        st = self.watchdog.end_step()
+                        self.metrics_log.append(
+                            {"step": step, "loss": loss, "grad_norm": gnorm,
+                             "sec": st.duration_s, "straggler": st.flagged})
+                        if step % self.log_every == 0 or step == self.steps - 1:
+                            print(f"[train] step {step:5d} loss {loss:.4f} "
+                                  f"gnorm {gnorm:.3f} "
+                                  f"({st.duration_s*1e3:.0f} ms)")
+                    next_step = step + 1
+                    if self.ckpt_every and next_step % self.ckpt_every == 0:
+                        self.save_ckpt(params, opt, next_step)
+                    if self._preempted:
+                        print("[train] SIGTERM: checkpointing and exiting")
+                        self.save_ckpt(params, opt, next_step)
+                        sys.exit(0)
+                    if self.fail_at_step is not None and next_step == self.fail_at_step:
+                        print(f"[train] injected failure at step {next_step}",
+                              flush=True)
+                        os._exit(42)   # simulate a hard node crash
             self.save_ckpt(params, opt, self.steps)
         finally:
             if ctx:

@@ -163,3 +163,75 @@ np.testing.assert_allclose(sharded, single, rtol=1e-2)
 print("OK")
 """, devices=4)
     assert "OK" in out
+
+
+def _host_spans(trace_dir):
+    """[name, start_ns, end_ns, step_num] of the `train.*` host events."""
+    import glob
+
+    import jax
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                out += [[e.name, e.start_ns, e.start_ns + e.duration_ns,
+                         dict(e.stats).get("step_num")]
+                        for e in line.events if e.name.startswith("train.")]
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+def test_run_spans_tile_each_step(tmp_path):
+    """Under a profiler trace each step is one `train.step` with its
+    `step_num`, holding data, dispatch, wait, fetch and log in that order;
+    `train.ckpt` marks only the saves and `train.compile` the one compile."""
+    import jax
+    tr = make_trainer(tmp_path / "ck", steps=4, ckpt_every=2)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        tr.run()
+    spans = _host_spans(str(tmp_path / "trace"))
+    steps = [s for s in spans if s[0] == "train.step"]
+    assert [s[3] for s in steps] == [0, 1, 2, 3]
+    phases = ["train.data", "train.dispatch", "train.wait", "train.fetch",
+              "train.log"]
+    for name, lo, hi, _ in steps:
+        inner = [s[0] for s in spans if lo <= s[1] and s[2] <= hi
+                 and s[0] in phases]
+        assert inner == phases
+    compiles = [s for s in spans if s[0] == "train.compile"]
+    assert len(compiles) == 1
+    (dispatch0,) = [s for s in spans if s[0] == "train.dispatch"
+                    and steps[0][1] <= s[1] < steps[0][2]]
+    assert dispatch0[1] <= compiles[0][1] and compiles[0][2] <= dispatch0[2]
+    ckpts = [s for s in spans if s[0] == "train.ckpt"]
+    in_step = [next((st[3] for st in steps if st[1] <= c[1] < st[2]), None)
+               for c in ckpts]
+    # saves after steps 1 and 3 (next step 2 and 4), then the final save
+    assert in_step == [1, 3, None]
+
+
+def test_step_hlo_names_the_model_parts():
+    """The compiled train step's op metadata carries the model's named
+    scopes (under jax's transform wrappers), with the layers rematerialised."""
+    import re
+
+    tr = Trainer(CFG, steps=1, batch=2, seq=32, ckpt_dir=None, ckpt_every=0,
+                 settings=StepSettings(accum=1, remat="full"))
+    params, opt, _ = tr.init_state(0)
+    batch = {k: jnp.asarray(v) for k, v in tr.data.batch_at(0).items()}
+    text = tr.compile(params, opt, batch).as_text()
+
+    def components(path):
+        out = set()
+        for c in path.split("/"):
+            while (m := re.fullmatch(r"[\w.\-]+\((.*)\)", c)):
+                c = m.group(1)
+            out.add(c)
+        return out
+
+    found = set().union(*(components(p) for p in
+                          re.findall(r'op_name="([^"]+)"', text)))
+    assert {"attn", "embed", "optimizer", "layer"} <= found
+    assert {"logits", "loss"} & found
+    assert "checkpoint" in found or "rematted_computation" in found
