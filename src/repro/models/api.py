@@ -82,12 +82,11 @@ def forward(cfg, params, batch, *, attn_impl="auto", remat="none"):
                                remat=remat)
 
 
-def loss_fn(cfg, params, batch, *, attn_impl="auto", remat="none",
-            embed_impl="onehot"):
+def loss_fn(cfg, params, batch, *, attn_impl="auto", remat="none"):
     """Training loss: fused head+xent on hidden states (no [B,S,V] logits)."""
     mod = encdec if cfg.family == "encdec" else transformer
     hidden, aux = mod.forward_hidden(cfg, params, batch, attn_impl=attn_impl,
-                                     remat=remat, embed_impl=embed_impl)
+                                     remat=remat)
     return fused_next_token_loss(cfg, params["embed"], hidden, batch, aux)
 
 

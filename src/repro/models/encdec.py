@@ -90,15 +90,14 @@ def _decoder_layers(cfg, params, x, positions, memory, *, remat="none",
     return x, caches
 
 
-def forward_hidden(cfg, params, batch, *, attn_impl="auto", remat="none",
-                   embed_impl="gather"):
+def forward_hidden(cfg, params, batch, *, attn_impl="auto", remat="none"):
     """Teacher-forced forward to decoder hidden states [B,S,D]."""
     memory = encode(cfg, params, batch["frame_embeds"], remat=remat)
     tokens = batch["tokens"]
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     x = L.embed_tokens(cfg, params["embed"], tokens,
-                       positions=positions + cfg.source_len, impl=embed_impl)
+                       positions=positions + cfg.source_len)
     x, _ = _decoder_layers(cfg, params, x, positions, memory, remat=remat)
     return L.apply_norm(cfg, params["final_norm"], x), jnp.zeros((), jnp.float32)
 

@@ -164,43 +164,18 @@ def embed_meta(cfg):
     return m
 
 
-def _embed_onehot(table: jax.Array, tokens: jax.Array, out_dtype,
-                  chunk: int = 256) -> jax.Array:
-    """Chunked one-hot-matmul embedding lookup.
+def embed_tokens(cfg, p, tokens: jax.Array, positions=None) -> jax.Array:
+    """Row lookup in the input table.
 
-    XLA's SPMD partitioner mis-partitions gathers whose indices arrive
-    scan-sliced inside a while loop while the operand is sharded (invalid
-    dynamic-slice after spmd-partitioning); einsum partitioning is robust
-    everywhere.  FLOP cost is 2·V·D per token — bounded by one extra LM-head
-    pass (<=5% of a training step for the assigned archs); the one-hot is
-    chunked over sequence and rematerialized in backward.
+    Its gradient is one scatter-add of the row cotangents into a zeroed
+    table of the table's dtype.  `TRAIN_RULES` never shard the table's
+    vocab dim (`in_vocab`), so the lookup needs no communication.
     """
-    from repro.models.attention import largest_divisor_leq
-    B, S = tokens.shape
-    V, D = table.shape
-    chunk = largest_divisor_leq(S, chunk)
-    n = S // chunk
-    tk = tokens.reshape(B, n, chunk).swapaxes(0, 1)
-
-    @jax.checkpoint
-    def body(_, t_c):
-        oh = jax.nn.one_hot(t_c, V, dtype=out_dtype)
-        return None, jnp.einsum("bcv,vd->bcd", oh, table.astype(out_dtype))
-
-    _, xs = jax.lax.scan(body, None, tk)                 # [n, B, chunk, D]
-    return xs.swapaxes(0, 1).reshape(B, S, D)
-
-
-def embed_tokens(cfg, p, tokens: jax.Array, positions=None,
-                 impl: str = "gather") -> jax.Array:
     from repro.distributed.autoshard import constrain, constrain_residual
     with jax.named_scope("embed"):
         cdt = jnp.dtype(cfg.compute_dtype)
-        if impl == "onehot":
-            x = _embed_onehot(p["in_table"], tokens, cdt)
-        else:
-            tokens = constrain(tokens, (None,) * tokens.ndim)
-            x = jnp.take(p["in_table"], tokens, axis=0).astype(cdt)
+        tokens = constrain(tokens, (None,) * tokens.ndim)
+        x = jnp.take(p["in_table"], tokens, axis=0).astype(cdt)
         if cfg.rope == "learned" and positions is not None:
             positions = constrain(positions, (None,) * positions.ndim)
             pe = jnp.take(p["pos_table"], positions, axis=0)

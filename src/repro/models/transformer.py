@@ -173,10 +173,9 @@ def apply_layers(cfg, stacked, x, positions, *, attn_impl="auto",
     return x, aux, caches
 
 
-def forward_hidden(cfg, params, batch, *, attn_impl="auto", remat="none",
-                   embed_impl="gather"):
+def forward_hidden(cfg, params, batch, *, attn_impl="auto", remat="none"):
     """Forward to final-norm hidden states [B,S,D]. Returns (hidden, aux)."""
-    x, positions = embed_inputs(cfg, params, batch, embed_impl=embed_impl)
+    x, positions = embed_inputs(cfg, params, batch)
     x, aux, _ = apply_layers(cfg, params["layers"], x, positions,
                              attn_impl=attn_impl, remat=remat)
     return L.apply_norm(cfg, params["final_norm"], x), aux
@@ -193,21 +192,20 @@ def forward(cfg, params, batch, *, attn_impl="auto", remat="none"):
     return logits, aux
 
 
-def embed_inputs(cfg, params, batch, embed_impl="gather"):
+def embed_inputs(cfg, params, batch):
     """Family-specific input embedding. Returns (x [B,S,D], positions)."""
     tokens = batch["tokens"]
     B = tokens.shape[0]
     if cfg.family == "vlm":
         patches = batch["patch_embeds"].astype(jnp.dtype(cfg.compute_dtype))
-        tok_x = L.embed_tokens(cfg, params["embed"], tokens, impl=embed_impl)
+        tok_x = L.embed_tokens(cfg, params["embed"], tokens)
         with jax.named_scope("vision_stub"):
             x = jnp.concatenate([patches, tok_x], axis=1)
         positions = batch["positions"]          # [3, B, S] m-rope ids
         return x, positions
     S = tokens.shape[1]
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
-    x = L.embed_tokens(cfg, params["embed"], tokens, positions=positions,
-                       impl=embed_impl)
+    x = L.embed_tokens(cfg, params["embed"], tokens, positions=positions)
     return x, positions
 
 

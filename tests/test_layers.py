@@ -120,15 +120,107 @@ def test_layernorm_matches_numpy():
 # embeddings
 # --------------------------------------------------------------------------
 
-def test_onehot_embed_matches_gather():
-    cfg = _cfg()
-    params = materialize(L.embed_meta(cfg), jax.random.PRNGKey(0))
-    toks = jnp.asarray(np.random.default_rng(6).integers(
-        0, cfg.vocab_size, (2, 24)), jnp.int32)
-    a = L.embed_tokens(cfg, params, toks, impl="gather")
-    b = L.embed_tokens(cfg, params, toks, impl="onehot")
-    np.testing.assert_allclose(np.asarray(a, np.float32),
-                               np.asarray(b, np.float32), atol=2e-2)
+# families that train through `api.loss_fn` with an untied input table
+EMBED_GRAD_ARCHS = ["chatglm3-6b", "mixtral-8x22b"]
+# 2 x 600 tokens cross the old 256-token chunk edges at 256 and 512; ids
+# below 40 repeat within and across chunks, ids 40.. never occur
+EMBED_B, EMBED_S, EMBED_USED = 2, 600, 40
+
+
+def _embed_grad_case(arch):
+    from repro.models import api
+    cfg = smoke_config(ARCHS[arch])
+    assert not cfg.tie_embeddings
+    params = api.init_params(cfg, 0)
+    tokens = np.random.default_rng(6).integers(
+        0, EMBED_USED, (EMBED_B, EMBED_S)).astype(np.int32)
+    tokens[:, 250:262] = 7                  # one id on both sides of 256
+    tokens[0, 512], tokens[1, 511] = 3, 3   # and across 512, between rows
+    return cfg, params, {"tokens": jnp.asarray(tokens)}
+
+
+@pytest.mark.parametrize("arch", EMBED_GRAD_ARCHS)
+def test_input_table_grad_is_row_scatter_add(arch, monkeypatch):
+    """d loss / d in_table is the f32 sum of each token's row cotangent,
+    and exactly zero on the rows of ids that never occur.
+
+    The row cotangents come from the same program: a zero probe added to
+    the lookup's output has the lookup's output cotangent as its gradient.
+    """
+    from repro.models import api
+    cfg, params, batch = _embed_grad_case(arch)
+    cdt = jnp.dtype(cfg.compute_dtype)
+    probe = jnp.zeros((EMBED_B, EMBED_S, cfg.d_model), cdt)
+    embed = L.embed_tokens
+
+    def probed_loss(params, probe):
+        monkeypatch.setattr(L, "embed_tokens",
+                            lambda *a, **k: embed(*a, **k) + probe)
+        return api.loss_fn(cfg, params, batch)
+
+    g_params, g_rows = jax.jit(jax.grad(probed_loss, argnums=(0, 1)))(
+        params, probe)
+    got = np.asarray(g_params["embed"]["in_table"])
+    assert got.dtype == np.float32
+    ref = np.zeros(got.shape, np.float64)
+    np.add.at(ref, np.asarray(batch["tokens"]),
+              np.asarray(g_rows, np.float64))
+    assert np.abs(ref[:EMBED_USED]).min(axis=1).max() > 0
+    # f32 sums of up to ~60 rows against float64: summation order only
+    np.testing.assert_allclose(got, ref, rtol=0,
+                               atol=1e-5 * np.abs(ref).max())
+    assert not got[EMBED_USED:].any()
+
+
+def _tainted_dots_and_loops(jaxpr, tainted):
+    """Equations of `jaxpr` (recursing into sub-jaxprs) that take a tainted
+    var, or its dtype cast, as an operand: (dot_general, loops) counts."""
+    from jax.extend import core as jcore
+    dots = loops = 0
+    tainted = set(tainted)
+    for eqn in jaxpr.eqns:
+        hit = any(v in tainted for v in eqn.invars
+                  if not isinstance(v, jcore.Literal))
+        name = eqn.primitive.name
+        if hit and name == "convert_element_type":
+            tainted.update(eqn.outvars)
+        if hit and name == "dot_general":
+            dots += 1
+        if hit and name in ("scan", "while"):
+            loops += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            # operands map onto a sub-jaxpr's trailing invars (scan: consts,
+            # carry, xs; while: cond consts, body consts, carry)
+            n = len(sub.invars)
+            inner = {iv for ov, iv in zip(eqn.invars[-n:], sub.invars)
+                     if not isinstance(ov, jcore.Literal) and ov in tainted}
+            if inner:
+                d, lo = _tainted_dots_and_loops(sub, inner)
+                dots, loops = dots + d, loops + lo
+    return dots, loops
+
+
+@pytest.mark.parametrize("arch", EMBED_GRAD_ARCHS)
+def test_train_step_embeds_by_lookup(arch):
+    """The train step's program multiplies nothing by the input table and
+    loops over nothing that holds it: no one-hot product, no chunked scan
+    carrying the table's gradient."""
+    from repro.launch.presets import StepSettings
+    from repro.launch.steps import make_train_step
+    from repro.optim import AdamWConfig, adamw
+    cfg, params, batch = _embed_grad_case(arch)
+    opt_cfg = AdamWConfig(lr=1e-3, warmup_steps=2, total_steps=10)
+    opt = adamw.init(opt_cfg, params)
+    step = make_train_step(cfg, opt_cfg, StepSettings(remat="full"))
+    closed = jax.make_jaxpr(step)(params, opt, batch)
+    paths = [jax.tree_util.keystr(p) for p, _ in
+             jax.tree_util.tree_flatten_with_path((params, opt, batch))[0]]
+    table = closed.jaxpr.invars[paths.index("[0]['embed']['in_table']")]
+    dots, loops = _tainted_dots_and_loops(closed.jaxpr, {table})
+    assert (dots, loops) == (0, 0)
+    # the walk does find a table that is multiplied inside a loop: the head
+    head = closed.jaxpr.invars[paths.index("[0]['embed']['out_head']")]
+    assert all(_tainted_dots_and_loops(closed.jaxpr, {head}))
 
 
 # --------------------------------------------------------------------------

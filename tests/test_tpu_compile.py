@@ -8,6 +8,7 @@ described inside a module fixture, never at import, so every xdist worker
 collects the same tests and only the worker given this file loads libtpu.
 Keep every compile for the described chip in this one file.
 """
+import dataclasses
 import os
 
 import jax
@@ -127,15 +128,18 @@ def test_train_step_one_chip_bringup(one_chip):
     assert trace.hlo_flops > 0
 
 
-def test_train_step_2x2_trace(topo):
+@pytest.mark.parametrize("accum", [1, 2])
+def test_train_step_2x2_trace(topo, accum):
     """FSDP+TP step on the 2x2 mesh: the trace shows the grad_sync
     all-reduce over `data` and the all-gathers over `model`, and both
-    ingest engines build the same store."""
+    ingest engines build the same store.  With `accum` 2 the input table's
+    lookup runs inside the accumulation scan on scan-sliced tokens."""
     cfg = get_config("chatglm3-6b").replace(num_layers=BRINGUP_LAYERS)
     mesh = make_mesh((2, 2), ("data", "model"), devices=topo.devices)
-    tr = Trainer(cfg, steps=3, batch=2, seq=BRINGUP_SEQ, mesh=mesh,
-                 settings=SETTINGS)
-    params, opt, batch = _trainer_step_specs(tr, 2, BRINGUP_SEQ)
+    batch_size = 2 * accum
+    tr = Trainer(cfg, steps=3, batch=batch_size, seq=BRINGUP_SEQ, mesh=mesh,
+                 settings=dataclasses.replace(SETTINGS, accum=accum))
+    params, opt, batch = _trainer_step_specs(tr, batch_size, BRINGUP_SEQ)
     with activation_sharding(mesh):
         compiled = tr.jit_step.lower(_on(params, tr.param_sh),
                                      _on(opt, tr.opt_sh), batch).compile()
