@@ -312,6 +312,8 @@ def run(cell: dict, cfg: dict, traffic: dict, args, devices, t_start: float,
         f"{[window_log[i]['sec'] * 1e3 for i in slow if i < len(window_log)]}",
         f"warm-up step ms {[round(m['sec'] * 1e3, 3) for m in b.trainer.metrics_log[:warmup]]}",
         gcs.note()]
+    # traced runs only: the executable's op-name paths, for the scope readers
+    scopes = trace.scope_map(b.trainer.compiled.as_text()) if tdir else None
     ring_np = b.ring_np
     del b, data
     gc.collect()
@@ -328,13 +330,17 @@ def run(cell: dict, cfg: dict, traffic: dict, args, devices, t_start: float,
            "checks": checks, "peak": peak, "chips": chips, "notes": notes}
     if tdir:
         rec = trace.load(tdir)
+        rec["scopes"] = scopes
         shutil.rmtree(tdir, ignore_errors=True)
         lo, hi = trace.window(rec)
         busy = trace.busy_ns(rec, lo, hi)
         if not busy:
             raise RuntimeError("the trace holds no device operations")
+        # what each reader in `metrics/` is given: the trace record with
+        # its scope map, the window's bounds (ns), device busy ns per chip,
+        # the window's steps and their `metrics_log` entries, and the cell
         out["trace"] = SimpleNamespace(
-            rec=rec, lo=lo, hi=hi, busy=busy, n_steps=n_steps,
+            rec=rec, lo=lo, hi=hi, busy=busy, n_steps=n_steps, log=window_log,
             tokens_per_s=tok_s, chips=chips, cfg=cfg, seq=traffic["seq"],
             device_kind=used[0].device_kind,
             flops_per_token=flops.train_flops_per_token(cfg, traffic["seq"]))

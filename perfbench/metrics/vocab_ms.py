@@ -1,0 +1,10 @@
+"""Own device ms per step of the ops in the program's `embed`, `logits`
+and `loss` scopes (the input table, the LM head, the cross-entropy),
+mean over chips; from the trace and the executable's scope map
+(`yardstick.trace.scope_ms`)."""
+from yardstick import trace
+
+
+def read(t):
+    return trace.scope_ms(t.rec, t.lo, t.hi, t.n_steps,
+                          ["embed", "logits", "loss"])

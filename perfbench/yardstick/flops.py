@@ -1,12 +1,13 @@
 """Model FLOPs of a training step, from the configuration's shapes.
 
-Per token, forward: 2 FLOPs per weight of every matrix product (the
-attention projections, the gated MLP and the LM head; the embedding is a
-lookup and counts nothing), plus attention's two products, QK^T and PV,
-at 2 * head_dim FLOPs per query head per key the token may attend to.
-Causal attention counts only the keys at or before the query, so a
-sequence of S tokens averages (S + 1) / 2 keys; a window of W caps each
-query's keys at W.  Training is forward plus backward, 3x the forward.
+Per token, forward: 2 FLOPs per weight of every matrix product, as the
+architecture module counts them (`matmul_flops_per_token`; an embedding
+is a lookup and counts nothing), plus attention's two products, QK^T and
+PV, at 2 * head_dim FLOPs per query head per key the token may attend to
+in each layer (`attention_keys`).  Causal attention counts only the keys
+at or before the query, so a sequence of S tokens averages (S + 1) / 2
+keys; a window of W caps each query's keys at W.  Training is forward
+plus backward, 3x the forward.
 Recomputation under remat counts nothing: it is work the model does not
 need.  The chip's peak comes from `peaks.json`, keyed by device kind.
 """
@@ -14,6 +15,8 @@ from __future__ import annotations
 
 import json
 from pathlib import Path
+
+from yardstick import arch
 
 PEAKS = json.loads((Path(__file__).with_name("peaks.json")).read_text())
 
@@ -26,18 +29,11 @@ def mean_keys(seq: int, window: int = 0) -> float:
     return (seq + 1) / 2
 
 
-def matmul_weights(cfg: dict) -> int:
-    D, F, V = cfg["d_model"], cfg["d_ff"], cfg["vocab_size"]
-    Q = cfg["num_heads"] * cfg["head_dim"]
-    KV = cfg["num_kv_heads"] * cfg["head_dim"]
-    per_layer = D * Q + 2 * D * KV + Q * D + 3 * D * F
-    return cfg["num_layers"] * per_layer + D * V
-
-
 def train_flops_per_token(cfg: dict, seq: int) -> float:
+    mod = arch.module(cfg)
     attn = 2 * 2 * cfg["num_heads"] * cfg["head_dim"] \
-        * mean_keys(seq, cfg.get("window", 0))
-    fwd = 2 * matmul_weights(cfg) + cfg["num_layers"] * attn
+        * sum(mod.attention_keys(cfg, seq))
+    fwd = mod.matmul_flops_per_token(cfg) + attn
     return 3.0 * fwd
 
 

@@ -14,16 +14,17 @@ Global-norm clipping needs every gradient before the first update, so each
 step walks the backward twice: once to sum the squares, once to update
 each leaf as soon as its gradient is complete.
 
-Model (dense decoder, as the configuration states): x = table[tokens];
-per layer x += Wo·attn(rope(Wq·n1(x)), rope(Wk·n1(x)), Wv·n1(x)) and
-x += Wd·(silu(Wg·n2(x)) * Wu·n2(x)) with n = RMSNorm(eps 1e-6); causal
-GQA attention (query head h reads key head h // (H/K)), scores scaled by
-head_dim**-0.5, keys further back than `window` masked when it is > 0;
-rope rotates the first `rope_fraction` of each head as two halves
-(x1, x2) -> (x1 cos - x2 sin, x2 cos + x1 sin) at angles pos *
-theta**(-i/n); loss = mean next-token NLL of RMSNorm(x)·W_head over the
-first S-1 positions.  AdamW with global-norm clipping, linear warm-up and
-cosine decay, moments stored in the stated dtype.
+Model, as the configuration states: x = table[tokens]; each layer is the
+architecture module's (`arch/<arch>.py`, `make_layer`), built from the
+helpers of `make_ops`: RMSNorm (eps 1e-6); causal GQA attention (query
+head h reads key head h // (H/K)), scores scaled by head_dim**-0.5, keys
+further back than a window masked when it is > 0; rope on the first
+`fraction` of each head as two halves (x1, x2) -> (x1 cos - x2 sin,
+x2 cos + x1 sin) at angles pos * theta**(-i/n).  Loss = mean next-token
+NLL of RMSNorm(x)·W_head over the first S-1 positions, plus each layer's
+auxiliary loss (a MoE router's) at weight `router_aux_coef / num_layers`,
+averaged over the batch rows.  AdamW with global-norm clipping, linear
+warm-up and cosine decay, moments stored in the stated dtype.
 
 `rnd` rounds the operands of every product and the embedded rows: the
 identity for the reference, `fp8` for the control (the reference one
@@ -32,6 +33,7 @@ precision below the bfloat16 the configuration computes in).
 from __future__ import annotations
 
 import math
+from types import SimpleNamespace
 from typing import Callable, Dict, List
 
 import numpy as np
@@ -39,12 +41,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from yardstick import weights
+from yardstick import arch, weights
 
 HI = jax.lax.Precision.HIGHEST
 _leaf = jax.jit(weights.leaf, static_argnums=(1, 2, 3))
-LAYER_LEAVES = ("attn/wq", "attn/wk", "attn/wv", "attn/wo", "mlp/w_gate",
-                "mlp/w_up", "mlp/w_down", "norm1/scale", "norm2/scale")
 
 
 def identity(x):
@@ -72,6 +72,64 @@ def lr_at(opt: dict, t: int) -> float:
                                + (1 - opt["min_lr_ratio"]) * cos)
 
 
+def _norm(x, s):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * s
+
+
+def _rope(x, pos, theta: float, fraction: float):
+    """Rotate the first `fraction` of each head of x [S, heads, Dh]."""
+    rot = int(x.shape[-1] * fraction)
+    rot -= rot % 2
+    if rot == 0:
+        return x
+    n = rot // 2
+    ang = pos[:, None] * theta ** (-jnp.arange(n, dtype=jnp.float32) / n)
+    c, s = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x1, x2 = x[..., :n], x[..., n:rot]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s,
+                            x[..., rot:]], axis=-1)
+
+
+def make_ops(rnd: Callable = identity) -> SimpleNamespace:
+    """The helpers an architecture module builds its layer from:
+
+      mm(spec, a, b)   einsum of the rounded operands at HIGHEST
+      rnd(x)           the rounding of `mm`'s operands
+      norm(x, scale)   RMSNorm, eps 1e-6
+      rope(x, pos, theta, fraction)
+      attention(q [S, H, Dh], k, v [S, K, Dh], window) -> [S, H * Dh]
+                       exact causal softmax, a block of queries at a time
+    """
+    def mm(spec, a, b):
+        return jnp.einsum(spec, rnd(a), rnd(b), precision=HI)
+
+    def attention(q, k, v, window: int):
+        S, H, Dh = q.shape
+        K = k.shape[1]
+        k = jnp.repeat(k, H // K, axis=1)
+        v = jnp.repeat(v, H // K, axis=1)
+        qb = _divisor(S, 512)
+        kpos = jnp.arange(S)
+
+        @jax.checkpoint
+        def block(args):
+            qi, start = args
+            sc = mm("qhd,khd->hqk", qi, k) * Dh ** -0.5
+            qpos = start + jnp.arange(qb)
+            ok = kpos[None, :] <= qpos[:, None]
+            if window:
+                ok &= (qpos[:, None] - kpos[None, :]) < window
+            sc = jnp.where(ok[None], sc, -jnp.inf)
+            return mm("hqk,khd->qhd", jax.nn.softmax(sc, axis=-1), v)
+
+        starts = jnp.arange(0, S, qb)
+        out = jax.lax.map(block, (q.reshape(S // qb, qb, H, Dh), starts))
+        return out.reshape(S, H * Dh)
+
+    return SimpleNamespace(mm=mm, rnd=rnd, norm=_norm, rope=_rope,
+                           attention=attention)
+
+
 class Reference:
     """Three (or any number of) steps of the plain training step."""
 
@@ -79,64 +137,11 @@ class Reference:
                  device=None):
         self.cfg, self.opt, self.rnd = cfg, opt, rnd
         self.device = device or jax.devices()[0]
-        H, K, Dh = cfg["num_heads"], cfg["num_kv_heads"], cfg["head_dim"]
-        theta = cfg.get("rope_theta", 10_000.0)
-        rot = int(Dh * cfg.get("rope_fraction", 1.0))
-        rot -= rot % 2
-        window = cfg.get("window", 0)
-
-        def mm(spec, a, b):
-            return jnp.einsum(spec, rnd(a), rnd(b), precision=HI)
-
-        def norm(x, s):
-            return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True)
-                                     + 1e-6) * s
-
-        def rope(x, pos):
-            if cfg.get("rope", "standard") in ("none", "learned") or rot == 0:
-                return x
-            n = rot // 2
-            ang = pos[:, None] * theta ** (-jnp.arange(n, dtype=jnp.float32) / n)
-            c, s = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-            x1, x2 = x[..., :n], x[..., n:rot]
-            return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s,
-                                    x[..., rot:]], axis=-1)
-
-        def attention(q, k, v):
-            S = q.shape[0]
-            k = jnp.repeat(k, H // K, axis=1)
-            v = jnp.repeat(v, H // K, axis=1)
-            qb = _divisor(S, 512)
-            kpos = jnp.arange(S)
-
-            @jax.checkpoint
-            def block(args):
-                qi, start = args
-                sc = mm("qhd,khd->hqk", qi, k) * Dh ** -0.5
-                qpos = start + jnp.arange(qb)
-                ok = kpos[None, :] <= qpos[:, None]
-                if window:
-                    ok &= (qpos[:, None] - kpos[None, :]) < window
-                sc = jnp.where(ok[None], sc, -jnp.inf)
-                return mm("hqk,khd->qhd", jax.nn.softmax(sc, axis=-1), v)
-
-            starts = jnp.arange(0, S, qb)
-            out = jax.lax.map(block, (q.reshape(S // qb, qb, H, Dh), starts))
-            return out.reshape(S, H * Dh)
-
-        def layer(p, x):
-            S = x.shape[0]
-            pos = jnp.arange(S, dtype=jnp.float32)
-            h = norm(x, p["norm1/scale"])
-            q = mm("sd,dz->sz", h, p["attn/wq"]).reshape(S, H, Dh)
-            k = mm("sd,dz->sz", h, p["attn/wk"]).reshape(S, K, Dh)
-            v = mm("sd,dz->sz", h, p["attn/wv"]).reshape(S, K, Dh)
-            x = x + mm("sz,zd->sd", attention(rope(q, pos), rope(k, pos), v),
-                       p["attn/wo"])
-            h = norm(x, p["norm2/scale"])
-            ff = jax.nn.silu(mm("sd,df->sf", h, p["mlp/w_gate"])) \
-                * mm("sd,df->sf", h, p["mlp/w_up"])
-            return x + mm("sf,fd->sd", ff, p["mlp/w_down"])
+        self.arch = arch.module(cfg)
+        self.leaves = tuple(self.arch.layer_leaves(cfg))
+        ops = make_ops(rnd)
+        mm, norm = ops.mm, ops.norm
+        layer = self.arch.make_layer(cfg, ops)
 
         def head_nll(w, s, x, tokens):
             """Sum over positions 0..S-2 of -log p(tokens[t+1])."""
@@ -159,9 +164,9 @@ class Reference:
                                                tgt.reshape(n, cb),
                                                keep.reshape(n, cb))))
 
-        def layer_vjp(p, x, dy):
-            _, pull = jax.vjp(layer, p, x)
-            return pull(dy)
+        def layer_vjp(p, x, dy, daux, l):
+            _, pull = jax.vjp(lambda p_, x_: layer(p_, x_, l), p, x)
+            return pull((dy, daux))
 
         def head_vjp(w, s, x, tokens, ct):
             nll, pull = jax.vjp(lambda w_, s_, x_: head_nll(w_, s_, x_, tokens),
@@ -180,12 +185,9 @@ class Reference:
                     m32.astype(sdt), v32.astype(sdt))
 
         self._embed = jax.jit(lambda t, tok: rnd(t[tok]))
-        self._layer = jax.jit(layer)
-        self._layer_vjp = jax.jit(layer_vjp)
+        self._layer = jax.jit(layer, static_argnums=2)
+        self._layer_vjp = jax.jit(layer_vjp, static_argnums=4)
         self._head_vjp = jax.jit(head_vjp)
-        self._table_grad = jax.jit(
-            lambda tok, dx: jnp.zeros((cfg["vocab_size"], dx.shape[-1]),
-                                      jnp.float32).at[tok].add(dx))
         self._embed_vjp = jax.jit(
             lambda t, tok, dx: jax.vjp(lambda t_: rnd(t_[tok]), t)[1](dx)[0])
         self._sumsq = jax.jit(lambda g: jnp.sum(g * g))
@@ -215,22 +217,26 @@ class Reference:
         self.seed = seed
 
     def layer_params(self, l: int):
-        return {n: self.p[(n, l)] for n in LAYER_LEAVES}
+        return {n: self.p[(n, l)] for n in self.leaves}
 
     # ---- one step --------------------------------------------------------
     def _walk(self, tokens: np.ndarray, visit):
         """Forward and backward over `tokens` [B, S]; hand each finished
-        gradient to `visit(key, grad)`.  Returns the summed NLL."""
+        gradient to `visit(key, grad)`.  Returns the loss."""
         B, S = tokens.shape
         L = self.cfg["num_layers"]
         ct = jnp.float32(1.0 / (B * (S - 1)))
+        aux_w = self.cfg.get("router_aux_coef", 0.0) / L / B
+        daux = jnp.float32(aux_w)
         toks = [jax.device_put(tokens[b], self.device) for b in range(B)]
         xs: List[List] = []
+        aux = 0.0
         for b in range(B):
             x = self._embed(self.p[("embed/in_table", None)], toks[b])
             row = [x]
             for l in range(L):
-                x = self._layer(self.layer_params(l), x)
+                x, a = self._layer(self.layer_params(l), x, l)
+                aux += float(a)
                 row.append(x)
             xs.append(row)
         nll = 0.0
@@ -251,9 +257,9 @@ class Reference:
             pl = self.layer_params(l)
             acc = None
             for b in range(B):
-                dp, dxs[b] = self._layer_vjp(pl, xs[b][l], dxs[b])
+                dp, dxs[b] = self._layer_vjp(pl, xs[b][l], dxs[b], daux, l)
                 acc = dp if acc is None else jax.tree.map(jnp.add, acc, dp)
-            for name in LAYER_LEAVES:
+            for name in self.leaves:
                 visit((name, l), acc[name])
             del acc
         gt = None
@@ -261,18 +267,17 @@ class Reference:
             g = self._embed_vjp(self.p[("embed/in_table", None)], toks[b], dxs[b])
             gt = g if gt is None else gt + g
         visit(("embed/in_table", None), gt)
-        return nll
+        return nll / (B * (S - 1)) + aux_w * aux
 
     def step(self, tokens: np.ndarray) -> dict:
         """One step on `tokens`; returns loss, pre-clip grad norm and the
         clipped gradient's squared norm per program leaf."""
-        B, S = tokens.shape
         sq: Dict = {}
 
         def add_sq(key, g):
             sq[key] = float(self._sumsq(g))
 
-        nll = self._walk(tokens, add_sq)
+        loss = self._walk(tokens, add_sq)
         gnorm = math.sqrt(sum(sq.values()))
         scale = min(1.0, self.opt["clip_norm"] / max(gnorm, 1e-9)) \
             if self.opt["clip_norm"] else 1.0
@@ -288,7 +293,7 @@ class Reference:
                 self.p[key], g, self.m[key], self.v[key], *args)
 
         self._walk(tokens, update)
-        return {"loss": nll / (B * (S - 1)), "grad_norm": gnorm,
+        return {"loss": loss, "grad_norm": gnorm,
                 "grad_sq": _by_leaf(sq, scale * scale)}
 
     def change_sq(self) -> Dict[str, float]:

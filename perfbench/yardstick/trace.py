@@ -8,19 +8,32 @@ operations.
 
 with the device operations of each chip (the "XLA Ops" line of each
 `/device:TPU:n` plane) and the benchmark's own host spans (`bench.*`).
+A v5e trace names each operation by its HLO instruction only; the
+driver adds what the executable's HLO text says of each (`scope_map`):
+
+    {"scopes": {op name: op_name path of its metadata}}
+
 Every reduction below reads that record only, so a small recorded one
-(`tests/data/`) checks them without a chip.
+(`tests/bench/data/`) checks them without a chip.
 """
 from __future__ import annotations
 
 import glob
+import re
 from pathlib import Path
-from typing import Dict, List, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 OPS_LINE = "XLA Ops"
 # the trace rounds picoseconds to ns: a body op may end 1 ns after its loop
 NEST_SLACK_NS = 10
 SPAN_PREFIX = "bench."
+
+# the program's named scopes, innermost first: an op counts for the first
+# of these that is a whole component of its op-name path
+SCOPES = ("attn", "embed", "logits", "loss", "optimizer", "mlp", "layer")
+_INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*?metadata=\{[^}]*?'
+                    r'op_name="((?:[^"\\]|\\.)*)"', re.M)
+_WRAPPED = re.compile(r"\w+\((.*)\)")
 
 Interval = Tuple[int, int]
 
@@ -149,3 +162,42 @@ def idle_gaps(rec: dict, lo: int, hi: int, n: int = 10) -> List[list]:
         out.append([min(inside)[1] if inside else "untraced", (e - s) * 1e-9])
     return out
 
+
+
+def scope_map(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: op_name path} of every instruction of an HLO
+    module's text that carries one."""
+    return {m.group(1): m.group(2) for m in _INSTR.finditer(hlo_text)}
+
+
+def scope_of(path: str) -> Optional[str]:
+    """The scope of an op-name path: its components with transform
+    wrappers stripped (`transpose(jvp(loss))` -> `loss`), then the first
+    of `SCOPES` that is one of them, whole.  None where none is."""
+    parts = set()
+    for c in path.split("/"):
+        while (m := _WRAPPED.fullmatch(c)):
+            c = m.group(1)
+        parts.add(c)
+    return next((s for s in SCOPES if s in parts), None)
+
+
+def scope_ms(rec: dict, lo: int, hi: int, n_steps: int,
+             names: Iterable[str]) -> Optional[float]:
+    """Own device ms per step of the ops whose scope is one of `names`,
+    mean over chips.  None without a scope map, or where no such op ran."""
+    scopes = rec.get("scopes")
+    if not scopes:
+        return None
+    names = set(names)
+    of = {}
+    planes = sorted_planes(rec)
+    tot, hit = 0, False
+    for p in planes:
+        for name, _iv, own, _kids in self_times(_ops(rec, p, lo, hi)):
+            if name not in of:
+                of[name] = scope_of(scopes.get(name, "")) in names
+            if of[name]:
+                tot += own
+                hit = True
+    return tot / len(planes) / n_steps * 1e-6 if hit else None

@@ -1,9 +1,9 @@
 """Weights made from the seed, on the device, in one jitted call.
 
-The layout is that of a dense decoder (GQA attention, gated MLP, RMSNorm,
-untied LM head) with layers stacked on a leading axis, leaf for leaf the
-tree the trainer takes; `drivers.train` checks the two agree.  The seed
-enters as traced key data, so one compiled program serves every seed.
+The layout is the architecture module's (`arch/<arch>.py`), with layers
+stacked on a leading axis, leaf for leaf the tree the trainer takes;
+`drivers.train` checks the two agree.  The seed enters as traced key
+data, so one compiled program serves every seed.
 
 Each leaf is `normal * fan_in**-0.5` (fan-in the second-last axis), the
 input table `normal`, norm scales ones: initial logits are O(1) and the
@@ -16,32 +16,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from yardstick import arch
+
 
 def layout(cfg: dict) -> dict:
     """{leaf path: (shape, init)} with init "normal", "table" or "ones"."""
-    if (cfg["family"] != "dense" or cfg.get("norm", "rmsnorm") != "rmsnorm"
-            or not cfg.get("glu", True) or cfg.get("qk_norm", False)
-            or cfg.get("tie_embeddings", False)
-            or cfg.get("sandwich_norm", False)):
-        raise ValueError(f"no weight layout for config {cfg['name']!r}")
-    L, D, V = cfg["num_layers"], cfg["d_model"], cfg["vocab_size"]
-    Q = cfg["num_heads"] * cfg["head_dim"]
-    KV = cfg["num_kv_heads"] * cfg["head_dim"]
-    F = cfg["d_ff"]
-    return {
-        "embed/in_table": ((V, D), "table"),
-        "embed/out_head": ((D, V), "normal"),
-        "final_norm/scale": ((D,), "ones"),
-        "layers/attn/wq": ((L, D, Q), "normal"),
-        "layers/attn/wk": ((L, D, KV), "normal"),
-        "layers/attn/wv": ((L, D, KV), "normal"),
-        "layers/attn/wo": ((L, Q, D), "normal"),
-        "layers/mlp/w_gate": ((L, D, F), "normal"),
-        "layers/mlp/w_up": ((L, D, F), "normal"),
-        "layers/mlp/w_down": ((L, F, D), "normal"),
-        "layers/norm1/scale": ((L, D), "ones"),
-        "layers/norm2/scale": ((L, D), "ones"),
-    }
+    return arch.module(cfg).layout(cfg)
 
 
 def key_data(seed: int) -> np.ndarray:

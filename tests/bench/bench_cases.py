@@ -29,12 +29,12 @@ def cell_files(workload: str):
 
 
 def tiny(workload: str = "chatglm3-6b.train-s2k.1chip", **over) -> dict:
-    """The cell's configuration at widths a CPU test can hold."""
+    """The cell's configuration at widths a CPU test can hold, as its
+    architecture module (`yardstick/arch/<arch>.py`) shrinks it."""
+    from yardstick import arch
+
     cfg, _ = cell_files(workload)
-    cfg.update(num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
-               head_dim=16, d_ff=96, vocab_size=512)
-    cfg.update(over)
-    return cfg
+    return dict(arch.module(cfg).tiny(cfg), **over)
 
 
 def tiny_traffic(workload: str = "chatglm3-6b.train-s2k.1chip", **over) -> dict:

@@ -6,25 +6,28 @@ reference, the checks against the cell's limits) at a tiny size on the
 CPU, with one fault planted in the program.
 """
 import argparse
+import importlib
 import time
 
 import jax
 import pytest
 
 from bench_cases import BENCH, DATA, bench_json, tiny, tiny_traffic
-from drivers import train
 from yardstick import compare
 
 CELLS = [c["name"] for c in bench_json()["workloads"]]
 
 
 def run_cell(cell, seed=2**31 + 3, batch=1):
+    """A run of the cell at its tiny size, through the driver its traffic
+    file names."""
     limits = compare.load_limits(BENCH, cell)
     assert limits, f"no limits for {cell}"
+    traffic = tiny_traffic(cell, batch=batch)
+    driver = importlib.import_module(f"drivers.{traffic['kind']}")
     args = argparse.Namespace(seed=seed, seconds=0.3, trace=0)
-    return train.run({"name": cell, "chips": 1}, tiny(cell),
-                     tiny_traffic(cell, batch=batch), args, jax.devices(),
-                     time.perf_counter(), limits)
+    return driver.run({"name": cell, "chips": 1}, tiny(cell), traffic, args,
+                      jax.devices(), time.perf_counter(), limits)
 
 
 def gaps(out):
@@ -39,6 +42,15 @@ def cell(request):
 @pytest.fixture(scope="module")
 def sound(cell):
     return gaps(run_cell(cell))
+
+
+def test_untraced_run_reads_no_hlo(monkeypatch, cell):
+    """Only traced runs read the executable's HLO text for the scope map."""
+    def no_text(self, *a, **k):
+        raise AssertionError("as_text() in an untraced run")
+
+    monkeypatch.setattr(jax.stages.Compiled, "as_text", no_text)
+    assert gaps(run_cell(cell))
 
 
 def test_state_returned_unchanged(monkeypatch, cell, sound):

@@ -1,8 +1,8 @@
 """The yardstick on the CPU: trace reduction, inputs, window arithmetic,
 FLOP count, weights and the reference."""
 import gzip
+import hashlib
 import json
-import math
 
 import numpy as np
 import pytest
@@ -10,9 +10,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from bench_cases import BENCH, DATA, bench_json, tiny
+from bench_cases import BENCH, DATA, bench_json, cell_files, tiny, tiny_traffic
 from drivers import train
-from yardstick import compare, flops, reference, tokens, trace, weights
+from yardstick import arch, compare, flops, reference, tokens, trace, weights
+from yardstick.arch import dense
 
 
 # ---- trace reduction ---------------------------------------------------
@@ -42,6 +43,75 @@ def test_trace_reduction_on_recorded_trace():
     # the trace rounds each op's ps to ns: a few ns of overlap per op
     assert own == pytest.approx(chip_busy, rel=1e-4)
     assert "bench.batch_at" not in want["gap_spans"]
+
+
+HLO = """\
+ENTRY %main {
+  %fusion.1 = f32[8]{0} fusion(%p), kind=kLoop, metadata={op_name="jit(step)/transpose(jvp(layer))/attn/dot_general" stack_frame_id=3}
+  %fusion.2 = f32[8]{0} fusion(%p), kind=kLoop, metadata={op_name="jit(step)/jvp(loss)/while/body/closed_call/checkpoint/logits/dot_general"}
+  %add.3 = f32[8]{0} add(%p, %p), metadata={op_name="jit(step)/jvp(loss)/div"}
+  %fusion.4 = f32[8]{0} fusion(%p), kind=kLoop, metadata={op_name="jit(step)/layer/mlp/mul"}
+  %fusion.5 = f32[8]{0} fusion(%p), kind=kLoop, metadata={op_name="jit(step)/jvp()/while/body/transpose(jvp(layer))/mul"}
+  %copy.6 = f32[8]{0} copy(%p)
+  %fusion.7 = f32[8]{0} fusion(%p), kind=kLoop, metadata={op_name="jit(step)/attn_decode/layers/mul"}
+  ROOT %while.8 = f32[8]{0} while(%p), condition=%c, body=%b, metadata={op_name="jit(step)/optimizer/while"}
+  %tokens.9 = s32[8]{0} parameter(0), metadata={op_name="batch[\\'tokens\\']"}
+}
+"""
+
+
+def test_scope_rule():
+    """Transform wrappers stripped, whole components only, the innermost
+    scope first; an op with no metadata or no scope counts in no metric."""
+    scopes = trace.scope_map(HLO)
+    assert scopes["fusion.1"] == "jit(step)/transpose(jvp(layer))/attn/dot_general"
+    assert scopes["tokens.9"] == "batch[\\'tokens\\']"
+    assert "copy.6" not in scopes
+    got = {k: trace.scope_of(v) for k, v in scopes.items()}
+    assert got == {"fusion.1": "attn", "fusion.2": "logits", "add.3": "loss",
+                   "fusion.4": "mlp", "fusion.5": "layer", "fusion.7": None,
+                   "while.8": "optimizer", "tokens.9": None}
+    # two chips, two steps; the loop's body op fusion.4 lies inside
+    # while.8, which keeps only its own 30 ns
+    ops = [["fusion.1", 0, 100], ["fusion.2", 100, 50], ["add.3", 150, 10],
+           ["copy.6", 160, 40], ["fusion.7", 200, 20],
+           ["while.8", 220, 80], ["fusion.4", 230, 50]]
+    rec = {"devices": {"/device:TPU:0": ops,
+                       "/device:TPU:1": [[n, 3 * s, 3 * d] for n, s, d in ops]},
+           "spans": [], "scopes": scopes}
+
+    def ms(*names):
+        return trace.scope_ms(rec, 0, 10_000, 2, names)
+    assert ms("attn") == pytest.approx((100 + 300) / 2 / 2 * 1e-6)
+    assert ms("embed", "logits", "loss") == pytest.approx(
+        (60 + 180) / 2 / 2 * 1e-6)
+    assert ms("optimizer") == pytest.approx((30 + 90) / 2 / 2 * 1e-6)
+    assert ms("mlp") == pytest.approx((50 + 150) / 2 / 2 * 1e-6)
+    assert ms("embed") is None              # no op of the scope ran
+    assert trace.scope_ms(dict(rec, scopes=None), 0, 10_000, 2,
+                          ["attn"]) is None
+
+
+def test_scope_map_of_a_compiled_step():
+    """On a tiny train step's compiled HLO (recorded on the CPU): every
+    scope of the program is found, backward ops under their wrappers."""
+    text = gzip.decompress((DATA / "step_hlo_tiny.txt.gz").read_bytes()).decode()
+    scopes = trace.scope_map(text)
+    by = {}
+    for name, path in scopes.items():
+        by.setdefault(trace.scope_of(path), []).append(path)
+    assert set(by) == set(trace.SCOPES) | {None}
+    for s, paths in by.items():
+        assert s is None or all(s in p for p in paths)
+        if s not in (None, "optimizer"):
+            # the model's backward: the scope inside `transpose(jvp(...))`
+            # or below it
+            assert any("transpose(" in p for p in paths), s
+    # the scan loops themselves, outside every scope
+    assert any(p.endswith("jvp()/while") for p in by[None])
+    # instruction names are the trace's op names: `%` and `ROOT` dropped
+    assert all(not n.startswith(("%", "ROOT")) for n in scopes)
+    assert len(scopes) > 1000
 
 
 def test_interval_arithmetic():
@@ -119,7 +189,7 @@ def test_flops_chatglm_by_hand():
     per_layer = D * 4096 + 2 * D * 256 + 4096 * D + 3 * D * F
     assert per_layer == 203_948_032
     n = 2 * per_layer + D * V
-    assert flops.matmul_weights(cfg) == n == 674_234_368
+    assert dense.matmul_weights(cfg) == n == 674_234_368
     attn = 2 * 2 * 32 * 128 * (2049 / 2)
     want = 3 * (2 * n + 2 * attn)
     assert flops.train_flops_per_token(cfg, 2048) == pytest.approx(want)
@@ -176,6 +246,32 @@ def test_reference_matches_program_loss_in_f32():
     assert got == pytest.approx(want, rel=1e-5)
 
 
+PARENT = json.loads((DATA / "yardstick_parent.json").read_text())
+
+
+@pytest.mark.parametrize("cell", sorted(PARENT))
+def test_yardstick_reproduces_its_record(cell):
+    """The reference's readings, the weights and the FLOP count are, to
+    the last bit, what the yardstick gave before its architecture parts
+    were split out (`data/record_yardstick.py`, CPU)."""
+    want = PARENT[cell]
+    cfg, traffic = tiny(cell), tiny_traffic(cell)
+    assert cfg == want["tiny_cfg"] and traffic == want["tiny_traffic"]
+    seed = want["seed"]
+    opt = dict(cfg["optimizer"], total_steps=traffic["steps"])
+    ring = tokens.token_ring(seed, traffic["compare_steps"], traffic["batch"],
+                             traffic["seq"], v_eff=traffic["v_eff"],
+                             structure=traffic["structure"])
+    assert reference.readings(cfg, opt, seed, list(ring)) == want["readings"]
+    flat = jax.jit(lambda k: weights.flat(cfg, k))(
+        jnp.asarray(weights.key_data(seed)))
+    assert {k: hashlib.sha256(np.asarray(v).tobytes()).hexdigest()
+            for k, v in flat.items()} == want["leaf_sha256"]
+    full, _ = cell_files(cell)
+    assert flops.train_flops_per_token(full, want["seq"]) \
+        == want["flops_per_token"]
+
+
 def test_reference_adam_by_hand():
     opt = {"lr": 1e-3, "warmup_steps": 20, "total_steps": 100,
            "min_lr_ratio": 0.1}
@@ -209,7 +305,9 @@ def test_each_cell_reports_its_metrics(cell):
     assert "setup_s" in e2e and len(e2e) >= 2 and per_layer
     assert all(h.reader(n) for n in per_layer)
     assert compare.load_limits(BENCH, cell)
-    assert cfg["name"] == c["config"] and traffic["kind"] == "train"
+    assert cfg["name"] == c["config"]
+    assert (BENCH / "drivers" / f"{traffic['kind']}.py").exists()
+    assert (BENCH / "yardstick" / "arch" / f"{arch.name(cfg)}.py").exists()
 
 
 def test_readers_on_recorded_trace():
@@ -218,7 +316,8 @@ def test_readers_on_recorded_trace():
     rec = _record()
     lo, hi = trace.window(rec)
     busy = trace.busy_ns(rec, lo, hi)
-    t = SimpleNamespace(rec=rec, lo=lo, hi=hi, busy=busy, n_steps=3,
+    t = SimpleNamespace(rec=rec, lo=lo, hi=hi, busy=busy,
+                        n_steps=rec["expect"]["steps"],
                         tokens_per_s=12000.0, chips=1,
                         device_kind="TPU v5 lite", flops_per_token=4.0e9)
     idle = h.reader("device_idle_pct")(t)
@@ -226,6 +325,28 @@ def test_readers_on_recorded_trace():
     assert idle == pytest.approx(100 * (1 - chip_busy / (hi - lo)))
     assert 0 < idle < 10
     assert h.reader("mfu")(t) == pytest.approx(100 * 4.0e9 * 12000 / 197e12)
+    # the scope readers, on the executable's scope map recorded beside the
+    # trace: each ms a step of its scopes' own device time, near what a
+    # reading by hand of longer windows of the same program gave (chatglm3,
+    # PERF.md section 5)
+    got = {m: h.reader(m)(t) for m in ("attention_ms", "vocab_ms",
+                                       "optimizer_ms")}
+    by = rec["expect"]["scope_ms"]
+    assert got == pytest.approx({
+        "attention_ms": by["attn"],
+        "vocab_ms": by["embed"] + by["logits"] + by["loss"],
+        "optimizer_ms": by["optimizer"]}, rel=1e-12)
+    assert got["attention_ms"] == pytest.approx(19.95, rel=0.05)
+    assert got["vocab_ms"] == pytest.approx(36.80, rel=0.05)
+    assert got["optimizer_ms"] == pytest.approx(32.62, rel=0.05)
+    # every scope's time and the unscoped rest tile the ops' own time
+    own = sum(o for _n, _iv, o, _k in trace.self_times(
+        trace._ops(rec, trace.sorted_planes(rec)[0], lo, hi)))
+    scoped = sum(v for v in by.values() if v) * t.n_steps * 1e6
+    assert 0.85 * own < scoped < own
+    # without a scope map the readers read nothing
+    bare = SimpleNamespace(**dict(vars(t), rec=dict(rec, scopes=None)))
+    assert all(h.reader(m)(bare) is None for m in got)
 
 
 def test_no_chip_no_result(tmp_path):
